@@ -55,6 +55,9 @@ _ARTIFACTS = {
 
 def main() -> None:
     t0 = time.time()
+    from repro.runtime import compile_cache
+
+    compile_cache.enable()
     from . import (bench_accuracy, bench_gemm, bench_lm, bench_lu,
                    bench_nonsquare, bench_sdp, bench_tile)
     from . import common
@@ -95,11 +98,12 @@ def main() -> None:
                 common.dump_json(art[0], prefix=art[1])
     print(f"# total {time.time() - t0:.0f}s")
     if failed:
-        # exit 0 on purpose: the artifact row + "# FAILED" comments carry
-        # the failure; a nonzero exit would skip CI's artifact upload and
-        # destroy the very perf trajectory this path preserves
+        # the artifacts (error rows included) are already written; the
+        # nonzero exit makes the failure visible, and CI's upload steps
+        # run on failure too (if: always())
         print(f"# FAILED: {', '.join(failed)} (see error rows)",
               flush=True)
+        raise SystemExit(1)
 
 
 if __name__ == '__main__':

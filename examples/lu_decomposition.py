@@ -10,16 +10,27 @@ import sys
 import time
 
 import numpy as np
+import jax
 import jax.numpy as jnp
 
 from repro.core import dd
 from repro.core.linalg import lu_solve, rgetrf
+from repro.runtime import compile_cache
+
+
+def _f64(v):
+    """A dd value rounded once to f64 (exact for f32 limbs)."""
+    return np.asarray(v.hi, np.float64) + np.asarray(v.lo, np.float64)
 
 
 def main(n: int = 128):
+    compile_cache.enable()
+    # the plan layer refuses f64 limbs on the TPU (XLA emulates f64 there)
+    limb = jnp.float32 if jax.default_backend() == "tpu" else jnp.float64
     rng = np.random.default_rng(1)
     a_np = rng.random((n, n))
-    a = dd.from_float(jnp.asarray(a_np))
+    a = dd.from_float(jnp.asarray(a_np, limb))
+    a_np = _f64(a)  # the matrix the limbs hold
 
     t0 = time.time()
     lu, piv = rgetrf(a, block=32)
@@ -28,7 +39,7 @@ def main(n: int = 128):
     print(f"rgetrf(n={n}, b=32): {t:.2f}s  ({gflops:.4f} binary128-GFlops; "
           f"paper Agilex: 2.5 GFlops at n=20000)")
 
-    lu_np = np.asarray(dd.to_float(lu))
+    lu_np = _f64(lu)
     l = np.tril(lu_np, -1) + np.eye(n)
     u = np.triu(lu_np)
     pa = a_np.copy()
@@ -39,8 +50,8 @@ def main(n: int = 128):
     # solve A x = b and compare residual against plain f64 LU
     x_true = rng.standard_normal((n, 1))
     b = a_np @ x_true
-    x = lu_solve(lu, piv, dd.from_float(jnp.asarray(b)))
-    r_dd = np.abs(a_np @ np.asarray(dd.to_float(x)) - b).max()
+    x = lu_solve(lu, piv, dd.from_float(jnp.asarray(b, limb)))
+    r_dd = np.abs(a_np @ _f64(x) - b).max()
     x64 = np.linalg.solve(a_np, b)
     r_64 = np.abs(a_np @ x64 - b).max()
     print(f"residual |Ax-b|: binary128-class {r_dd:.3e}  vs double {r_64:.3e}")

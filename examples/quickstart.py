@@ -9,10 +9,12 @@ import jax.numpy as jnp
 from repro.core import dd
 from repro.core.blas import rgemm
 from repro.core.gemm import matmul
+from repro.runtime import compile_cache
 from repro.solve import rgesv
 
 
 def main():
+    compile_cache.enable()
     rng = np.random.default_rng(0)
     n = 96
     a = dd.from_float(jnp.asarray(rng.random((n, n))))

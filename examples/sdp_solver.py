@@ -10,9 +10,11 @@ ways and prints the Table-V-style comparison: double stalls near 1e-8..
 import time
 
 from repro.core.sdp import solve_sdp, theta_problem
+from repro.runtime import compile_cache
 
 
 def main():
+    compile_cache.enable()
     prob = theta_problem(8, 0.4, seed=2)
     print(f"Lovasz theta SDP: n={prob.n}, m={prob.m} constraints\n")
 

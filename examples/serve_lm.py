@@ -4,6 +4,8 @@
 """
 
 from repro.launch.serve import main
+from repro.runtime import compile_cache
 
 if __name__ == "__main__":
+    compile_cache.enable()
     main()

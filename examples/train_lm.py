@@ -14,9 +14,11 @@ import numpy as np
 
 from repro.configs.base import RunConfig
 from repro.launch.train import train
+from repro.runtime import compile_cache
 
 
 def main():
+    compile_cache.enable()
     ap = argparse.ArgumentParser()
     ap.add_argument("--steps", type=int, default=300)
     ap.add_argument("--batch", type=int, default=4)

@@ -34,7 +34,7 @@ from . import mp
 
 __all__ = ["GATES", "GATED_BACKENDS", "hilbert_f64",
            "hilbert_relative_error", "accuracy_report",
-           "write_accuracy_json", "max_rel_err",
+           "write_accuracy_json", "max_rel_err", "tier_ulp",
            "frac_matrix", "frac_matmul", "frac_sub", "frac_max_abs"]
 
 # per-tier observed-relative-error ceilings (the regression gate).  The
@@ -58,6 +58,19 @@ def hilbert_f64(n: int) -> np.ndarray:
     """Hilbert matrix H_ij = 1/(i+j+1), rounded once to f64."""
     i = np.arange(n, dtype=np.float64)
     return 1.0 / (i[:, None] + i[None, :] + 1.0)
+
+
+# bits short of k*p that one engine FMA loses per tier (dd: two_prod's
+# slack dominates; td/qd: the O(eps^k) renormalization truncation)
+_ULP_DEFICIT = {"dd": 2, "td": 4, "qd": 7}
+
+
+def tier_ulp(precision: str, limb_dtype=jnp.float64) -> float:
+    """Per-FMA unit roundoff of a tier over its limbs: the conformance
+    bound is 16 * k * tier_ulp (2^-104 / 2^-155 / 2^-205 over f64 limbs,
+    2^-46 / 2^-68 / 2^-89 over f32)."""
+    p = 53 if jnp.dtype(limb_dtype) == jnp.float64 else 24
+    return 2.0 ** -(mp.PRECISIONS[precision] * p - _ULP_DEFICIT[precision])
 
 
 def max_rel_err(got, want) -> float:

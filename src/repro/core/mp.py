@@ -443,16 +443,13 @@ def sum_limbs(al: Sequence, axis=None, keepdims=False) -> list:
     cur = [jnp.moveaxis(l, axis, 0) for l in al]
     m = cur[0].shape[0]
     while m > 1:
-        half = m // 2
-        even = [l[: 2 * half : 2] for l in cur]
-        odd = [l[1 : 2 * half : 2] for l in cur]
-        red = add_limbs(even, odd)
         if m % 2:
-            tail = [jnp.concatenate([l[-1:], jnp.zeros_like(r[1:])], 0)
-                    for l, r in zip(cur, red)]
-            red = add_limbs(red, tail)
-        cur = red
-        m = half
+            # an odd count pairs its last term with an exact zero
+            pad = [(0, 1)] + [(0, 0)] * (cur[0].ndim - 1)
+            cur = [jnp.pad(l, pad) for l in cur]
+            m += 1
+        cur = add_limbs([l[0::2] for l in cur], [l[1::2] for l in cur])
+        m //= 2
     out = [l[0] for l in cur]
     if keepdims:
         out = [jnp.expand_dims(l, axis) for l in out]

@@ -46,10 +46,10 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
-from . import dd, mp
+from . import dd, efts, mp
 
-__all__ = ["ozaki_gemm", "slice_count", "slice_bits", "slice_params",
-           "platform_dtypes"]
+__all__ = ["ozaki_gemm", "slab_update", "slice_count", "slice_bits",
+           "slice_params", "platform_dtypes"]
 
 
 def platform_dtypes(platform: str):
@@ -176,13 +176,11 @@ def _extract_slices(x, beta: int, n_slices: int, axis: int):
 
 
 def _pow2_near(mu):
-    """Exact power of two ~mu: mu / mantissa(mu) == 2^exponent(mu), exactly."""
-    # the floor keeps frexp off zero without ever over/underflowing the
-    # limb dtype (2^-511 is not representable in f32)
+    """Exact power of two just above mu: 2^(floor(log2 mu) + 1)."""
+    # the floor keeps the search off zero without ever over/underflowing
+    # the limb dtype (2^-511 is not representable in f32)
     floor = 2.0 ** -511 if jnp.dtype(mu.dtype) == jnp.float64 else 2.0 ** -63
-    mu = jnp.maximum(mu, floor)
-    m, _ = jnp.frexp(mu)  # mu = m * 2^e, m in [0.5, 1)
-    return mu / m
+    return 2.0 * efts.pow2_floor(jnp.maximum(mu, floor))
 
 
 def _diagonal_pairs(d: int, n_slices: int):
@@ -211,58 +209,80 @@ def _normalize_slices(slices, beta: int, axis: int, slice_dtype):
             for i, s in enumerate(slices)], sc
 
 
-def _fold_diagonal_sum(acc, dsum):
+def _fold_diagonal(acc, dsum):
     """acc += one diagonal's native-dtype sum, in acc's own tier.
 
     dd keeps its cheap ``add_float`` fold; wider counts distill the
     (k+1)-term list — cheaper than lifting ``dsum`` to a full tier add.
+    A sum wider than the limbs (f64 accumulation into f32 limbs) is split
+    off exactly into a second limb first, so the narrowing loses nothing.
     """
-    if isinstance(acc, dd.DD):
-        return dd.add_float(acc, dsum)
-    k = len(acc.limbs())
+    limb_dtype = mp.limbs(acc)[0].dtype
+    k = mp.nlimbs(acc)
+    if dsum.dtype == limb_dtype:
+        if isinstance(acc, dd.DD):
+            return dd.add_float(acc, dsum)
+        parts = [dsum]
+    else:
+        hi = dsum.astype(limb_dtype)
+        lo = (dsum - hi.astype(dsum.dtype)).astype(limb_dtype)
+        if isinstance(acc, dd.DD):
+            return dd.add(acc, dd.from_hi_lo(hi, lo))
+        parts = [hi, lo]
     return mp.from_limbs(
-        mp.renorm_list(list(acc.limbs()) + [dsum], k=k, sweeps=3))
+        mp.renorm_list(list(mp.limbs(acc)) + parts, k=k, sweeps=3))
+
+
+def slab_update(acc, a, b, *, beta: int, n_slices: int, slice_dtype,
+                acc_dtype, full: bool):
+    """acc += A @ B by error-free slicing — the recombination loop shared
+    by the whole-K path and the fused kernel's per-K-slab step.
+
+    Diagonal-grouped, most-significant diagonal first: the d+1 pair dots
+    of diagonal d sum in ``acc_dtype`` — exact by the slice_params
+    headroom — then ONE multi-limb fold per diagonal instead of one per
+    slice pair.  (Separate pair dots beat one concatenated (m,(d+1)k) dot
+    on xla:cpu by ~2.5x: the concat copies defeat the contraction's fast
+    path; the summation is exact either way.)
+    """
+    limb_dtype = mp.limbs(a)[0].dtype
+    sa = _extract_slices(a, beta, n_slices, axis=1)
+    sb = _extract_slices(b, beta, n_slices, axis=0)
+    narrow = jnp.dtype(slice_dtype) != jnp.dtype(limb_dtype)
+    if narrow:
+        # exact ladder normalization into the narrow dtype; pair (s, t)
+        # then carries the residual factor 2^(-(s+t)*beta), one rescale
+        # per diagonal, applied in the wider of the accumulator and limb
+        # dtypes so no bit of the exact diagonal sum is rounded away
+        sa, sc_a = _normalize_slices(sa, beta, 1, slice_dtype)
+        sb, sc_b = _normalize_slices(sb, beta, 0, slice_dtype)
+        wide = jnp.promote_types(acc_dtype, limb_dtype)
+        sc = sc_a.astype(wide) * sc_b.astype(wide)
+    n_diag = (2 * n_slices - 1) if full else n_slices
+    for d in range(n_diag):
+        dsum = None
+        for s, t in _diagonal_pairs(d, n_slices):
+            p = jnp.dot(sa[s], sb[t],
+                        preferred_element_type=jnp.dtype(acc_dtype))
+            dsum = p if dsum is None else dsum + p
+        if narrow:
+            dsum = dsum.astype(wide) * (sc * (2.0 ** (-d * beta)))
+        acc = _fold_diagonal(acc, dsum)
+    return acc
 
 
 @partial(jax.jit, static_argnames=("slice_dtype_name", "acc_dtype_name",
                                    "n_slices", "beta", "full"))
 def _ozaki_impl(*ab_limbs, slice_dtype_name: str,
                 acc_dtype_name: str, n_slices: int, beta: int, full: bool):
-    slice_dtype = jnp.dtype(slice_dtype_name)
-    acc_dtype = jnp.dtype(acc_dtype_name)
     nlimbs = len(ab_limbs) // 2
     a = mp.from_limbs(ab_limbs[:nlimbs])
     b = mp.from_limbs(ab_limbs[nlimbs:])
-    limb_dtype = ab_limbs[0].dtype
-    sa = _extract_slices(a, beta, n_slices, axis=1)
-    sb = _extract_slices(b, beta, n_slices, axis=0)
-
-    narrow = jnp.dtype(slice_dtype) != jnp.dtype(limb_dtype)
-    if narrow:
-        # exact ladder normalization into the narrow dtype (the scales are
-        # exact powers of two: xla:cpu's log2 is approximate under jit, so
-        # _pow2_near derives them as mu / frexp_mantissa(mu) instead)
-        sa, sc_a = _normalize_slices(sa, beta, 1, slice_dtype)
-        sb, sc_b = _normalize_slices(sb, beta, 0, slice_dtype)
-
-    m, n = mp.limbs(a)[0].shape[0], mp.limbs(b)[0].shape[1]
-    acc = mp.zeros((m, n), mp.precision_of(a), dtype=limb_dtype)
-    # diagonal-grouped recombination, most-significant diagonal first: the
-    # d+1 pair dots of diagonal d sum in acc_dtype — exact by the
-    # slice_params headroom — then ONE multi-limb fold per diagonal instead
-    # of one per slice pair.  (Separate pair dots beat one concatenated
-    # (m,(d+1)k) dot on xla:cpu by ~2.5x: the concat copies defeat the
-    # contraction's fast path; the summation is exact either way.)
-    n_diag = (2 * n_slices - 1) if full else n_slices
-    for d in range(n_diag):
-        dsum = None
-        for s, t in _diagonal_pairs(d, n_slices):
-            p = jnp.dot(sa[s], sb[t], preferred_element_type=acc_dtype)
-            dsum = p if dsum is None else dsum + p
-        if narrow:
-            dsum = dsum.astype(limb_dtype) * \
-                (sc_a * sc_b * (2.0 ** (-d * beta)))
-        acc = _fold_diagonal_sum(acc, dsum.astype(limb_dtype))
+    m, n = ab_limbs[0].shape[0], ab_limbs[nlimbs].shape[1]
+    acc = mp.zeros((m, n), mp.precision_of(a), dtype=ab_limbs[0].dtype)
+    acc = slab_update(acc, a, b, beta=beta, n_slices=n_slices,
+                      slice_dtype=jnp.dtype(slice_dtype_name),
+                      acc_dtype=jnp.dtype(acc_dtype_name), full=full)
     return tuple(mp.limbs(acc))
 
 

@@ -141,7 +141,8 @@ def autotune(m: int, k: int, n: int, *, dtype=jnp.float64,
     """
     dtype = jnp.dtype(dtype)
     nlimbs = PRECISIONS[precision]
-    backend = resolve_backend(backend)  # key the cache on the resolved name
+    # key the cache on the resolved name
+    backend = resolve_backend(backend)
     cache = cache or plan_cache.default_cache()
     if candidates is not None:
         candidates = list(candidates)

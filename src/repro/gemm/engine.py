@@ -88,28 +88,6 @@ def _pad_to(x, rows, cols):
     return jnp.pad(x, pad)
 
 
-# optimization_barrier has no batching rule in jax 0.4.x; it is identity
-# on values, so vmap passes straight through (the batched-GEMM vmap over
-# _pad would otherwise raise NotImplementedError).  jax >= 0.5 registers
-# its own rule, making this shim obsolete — the absence guard below keeps
-# us from overriding it.  Registration mutates a private jax dict, so it
-# is best-effort: if the internals move, _pad falls back to skipping the
-# barrier under vmap (see the NotImplementedError handler there), which
-# costs const-closure bit-reproducibility for batched operands, never
-# correctness.
-try:
-    from jax.interpreters import batching as _batching  # noqa: E402
-
-    if jax.lax.optimization_barrier_p not in _batching.primitive_batchers:
-        def _ob_batch(vals, dims):
-            return jax.lax.optimization_barrier_p.bind(*vals), dims
-
-        _batching.primitive_batchers[jax.lax.optimization_barrier_p] = \
-            _ob_batch
-except Exception:  # pragma: no cover - depends on jax internals moving
-    pass
-
-
 def _pad(x, rows, cols):
     r, c = x.shape[-2:]
     if r == rows and c == cols:
@@ -124,13 +102,8 @@ def _pad(x, rows, cols):
     # first seen on interpret-mode ozaki-pallas).  Pinning the pad output
     # makes the compiled graph per-op-faithful, so jit(const-closure),
     # jit(args), and eager all produce identical limbs.
-    try:
-        return mp.from_limbs(jax.lax.optimization_barrier(
-            tuple(mp.limbs(padded))))
-    except NotImplementedError:
-        # under vmap on a jax whose batching registry rejected our shim:
-        # skip the barrier rather than fail (trace-time fallback)
-        return padded
+    return mp.from_limbs(jax.lax.optimization_barrier(
+        tuple(mp.limbs(padded))))
 
 
 # --------------------------------------------------------------------------
@@ -171,11 +144,12 @@ def _ozaki_pallas_params(plan: GemmPlan, bk: int):
     acc_dtype = jnp.dtype(plan.acc_dtype) if plan.acc_dtype else jnp.float64
     beta, n_slices = plan.slice_beta, plan.n_slices
     if beta is None or n_slices is None:
-        from .plan import OZAKI_TARGET_BITS
+        from .plan import ozaki_target_bits
 
         beta, n_slices = _ozaki.slice_params(
             bk, acc_dtype, slice_dtype,
-            target_bits=plan.target_bits or OZAKI_TARGET_BITS[plan.precision],
+            target_bits=plan.target_bits or ozaki_target_bits(
+                plan.precision, plan.limb_dtype),
             n_slices=n_slices, beta=beta)
     return beta, n_slices, slice_dtype.name, acc_dtype.name
 
@@ -239,9 +213,10 @@ def _execute_2d(plan: GemmPlan, a, b):
         elif plan.precision != "dd":
             # hand-built plan without a solved target: cover the tier's own
             # significand, not ozaki_gemm's dd-oriented default
-            from .plan import OZAKI_TARGET_BITS
+            from .plan import ozaki_target_bits
 
-            kw["target_bits"] = OZAKI_TARGET_BITS[plan.precision]
+            kw["target_bits"] = ozaki_target_bits(plan.precision,
+                                                  plan.limb_dtype)
         if plan.full is not None:
             kw["full"] = plan.full
         return ozaki_gemm(a, b, **kw)
@@ -468,7 +443,6 @@ def _summa_runner(plan: GemmPlan, m: int, k: int, n: int, nl: int):
     as an operand is what lets the out-of-core K-streamer continue the
     *same* left-to-right panel fold across host-sliced chunks.
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     mesh, ax_m, ax_n = plan.mesh, plan.shard_axis, plan.shard_axis_n
@@ -589,14 +563,14 @@ def _summa_runner(plan: GemmPlan, m: int, k: int, n: int, nl: int):
         return jax.lax.fori_loop(0, steps, step, tuple(mp.limbs(acc0)))
 
     blk = P(ax_m, ax_n)
-    run = shard_map(
+    run = jax.shard_map(
         local, mesh=mesh,
         in_specs=(blk,) * (3 * nl),
         # the output stays 2-D block-sharded: each device drains its own C'
         # block, no all-gather — consumers slice or keep computing
         # shard-local (the paper's independent per-PE Feed/Drain)
         out_specs=(blk,) * nl,
-        check_rep=False,
+        check_vma=False,
     )
     return run, (mpad, npad, kpad)
 

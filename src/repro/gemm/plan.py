@@ -29,6 +29,7 @@ from typing import Any, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from repro.core.efts import TPU_F64_REFUSAL
 # the arithmetic layer owns the tier -> limb-count map (re-exported below
 # so plan consumers need not import core); also enables x64 on import
 from repro.core.mp import PRECISIONS
@@ -38,7 +39,8 @@ from . import cache as plan_cache
 
 __all__ = ["GemmPlan", "make_plan", "replan_precision", "resolve_backend",
            "round_up", "BACKENDS", "PRECISIONS", "DEFAULT_BLOCKS",
-           "OZAKI_TARGET_BITS", "FALLBACK_CHAINS", "fallback_chain"]
+           "OZAKI_TARGET_BITS", "ozaki_target_bits", "TPU_PALLAS_BLOCKS",
+           "FALLBACK_CHAINS", "fallback_chain"]
 
 BACKENDS = ("auto", "pallas", "ozaki", "ozaki-pallas", "xla", "ref")
 
@@ -78,10 +80,18 @@ def fallback_chain(backend: str, precision: str = "dd"):
 # carry solved (slice_beta, n_slices) so kernels never re-derive them
 _SLICED_BACKENDS = ("ozaki", "ozaki-pallas")
 
-# default significand coverage per tier for the slicing backends: dd is
-# binary128-class (the paper's format), td the 3-limb ~159-bit middle rung,
-# qd the 4-limb ~212-bit tier
+# default significand coverage per tier for the slicing backends over f64
+# limbs: dd is binary128-class (the paper's format), td the 3-limb
+# ~159-bit middle rung, qd the 4-limb ~212-bit tier.  Over f32 limbs each
+# tier covers its own k*24+1 bits (see ozaki_target_bits)
 OZAKI_TARGET_BITS = {"dd": 107, "td": 159, "qd": 212}
+
+
+def ozaki_target_bits(precision: str, limb_dtype) -> int:
+    """Significand bits a sliced product must cover for a tier's limbs."""
+    if jnp.dtype(limb_dtype) == jnp.float64:
+        return OZAKI_TARGET_BITS[precision]
+    return PRECISIONS[precision] * 24 + 1
 
 # (bm, bn, bk) heuristic defaults: the "8x16 PE / M_Tile=512" analogue from
 # the bench_tile sweep — VMEM cost = (bm*bk + bk*bn + 2*bm*bn) * 2 limbs * 4B.
@@ -90,8 +100,31 @@ OZAKI_TARGET_BITS = {"dd": 107, "td": 159, "qd": 212}
 # this module importing pallas eagerly.
 DEFAULT_BLOCKS = {"bm": 128, "bn": 128, "bk": 16}
 
+# Mosaic tiles every 32-bit block as (8, 128): the last dim of each Pallas
+# block (bk of A's, bn of B's and C's) must be a multiple of 128, the one
+# before it a multiple of 8, unless the block spans the whole (padded)
+# array.  On the TPU the Pallas backends default to this aligned depth.
+TPU_PALLAS_BLOCKS = {"bm": 128, "bn": 128, "bk": 128}
+_PALLAS_BACKENDS = ("pallas", "ozaki-pallas")
+
 _ENV_BACKEND = "REPRO_GEMM_BACKEND"
 _DEFAULT_BACKEND = "ozaki"
+# on the TPU the default rides the MXU through the fused slicing kernel
+_DEFAULT_BACKEND_TPU = "ozaki-pallas"
+
+# What the TPU refuses, found by compiling for a described v5e and by the
+# f64 probe of chip_smoke.py; make_plan raises these before anything is
+# traced, so no refused pair reaches the engine's failover walk.  (f64
+# limbs are refused again wherever an EFT is lowered for the TPU, which
+# covers the paths that plan no GEMM: see core.efts.)
+TPU_REF_REFUSAL = (
+    "backend 'ref' is refused on the TPU: the oracle holds all m*k*n tier "
+    "products at once and exists to check other backends on the CPU")
+TPU_XLA_REFUSAL = (
+    "backend 'xla' is refused on the TPU: its tier dot materializes "
+    "(m, bk, n) tier products in HBM, and at n=4096 the TPU compiler asks "
+    "5.1 GB for dd, 15.6 GB for td and 23.3 GB for qd of a v5e's 15.75 GB; "
+    "use ozaki-pallas or pallas")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -135,9 +168,12 @@ class GemmPlan:
         return dataclasses.replace(self, **changes)
 
 
-def resolve_backend(backend: str = "auto") -> str:
+def resolve_backend(backend: str = "auto",
+                    platform: Optional[str] = None) -> str:
+    platform = platform or jax.default_backend()
+    default = _DEFAULT_BACKEND_TPU if platform == "tpu" else _DEFAULT_BACKEND
     be = backend if backend != "auto" else os.environ.get(
-        _ENV_BACKEND, _DEFAULT_BACKEND)
+        _ENV_BACKEND, default)
     if be not in BACKENDS or be == "auto":
         raise ValueError(f"unknown GEMM backend {be!r}; one of {BACKENDS}")
     return be
@@ -145,6 +181,29 @@ def resolve_backend(backend: str = "auto") -> str:
 
 def round_up(x: int, b: int) -> int:
     return -(-x // b) * b
+
+
+def _tpu_refusal(backend: str, dtype, blocks: dict, m: int, k: int,
+                 n: int) -> Optional[str]:
+    """Why the TPU cannot run this plan, or None when it compiles there."""
+    if jnp.dtype(dtype) == jnp.float64:
+        return TPU_F64_REFUSAL
+    if backend == "ref":
+        return TPU_REF_REFUSAL
+    if backend == "xla":
+        return TPU_XLA_REFUSAL
+    if backend in _PALLAS_BACKENDS:
+        # a block that spans its whole padded dimension is exempt from
+        # the (8, 128) rule; any narrower block must be aligned
+        bad = [f"{x}={blocks[x]}"
+               for x, q, ext in (("bm", 8, m), ("bn", 128, n),
+                                 ("bk", 128, k))
+               if blocks[x] % q and blocks[x] < round_up(ext, 8)]
+        if bad:
+            return (f"Pallas blocks {', '.join(bad)} break the TPU's (8, "
+                    f"128) tiling: a block narrower than its padded "
+                    f"dimension needs bm % 8 == 0 and bn, bk % 128 == 0")
+    return None
 
 
 def _clamp_blocks(m: int, k: int, n: int, blocks: dict) -> dict:
@@ -188,7 +247,8 @@ def make_plan(m: int, k: int, n: int, *, dtype=jnp.float64,
     if check not in _CHECK_LEVELS:
         raise ValueError(f"unknown check level {check!r}; "
                          f"one of {_CHECK_LEVELS}")
-    be = resolve_backend(backend)
+    platform = platform or jax.default_backend()
+    be = resolve_backend(backend, platform)
     if precision == "qd" and be == "ozaki":
         if backend == "ozaki":
             # explicit request: fail loudly — whole-K slicing for a 212-bit
@@ -198,7 +258,6 @@ def make_plan(m: int, k: int, n: int, *, dtype=jnp.float64,
                 "backend 'ozaki' has no qd tier (slice count explodes past "
                 "the 212-bit target); use ozaki-pallas, pallas, xla, or ref")
         be = "xla"  # 'auto'/env default 'ozaki' is a dd-oriented hint
-    platform = platform or jax.default_backend()
     dtype = jnp.dtype(dtype)
 
     # quarantine consult: a backend that recently failed at compile/run
@@ -264,7 +323,8 @@ def make_plan(m: int, k: int, n: int, *, dtype=jnp.float64,
             n_l = -(-n // mesh.shape[shard_axis_n])
 
     source = "heuristic"
-    blocks = dict(DEFAULT_BLOCKS)
+    tpu_pallas = platform == "tpu" and be in _PALLAS_BACKENDS
+    blocks = dict(TPU_PALLAS_BLOCKS if tpu_pallas else DEFAULT_BLOCKS)
     if use_cache and be in ("pallas", "xla", "ozaki-pallas") \
             and (bm, bn, bk) == (None,) * 3:
         key = plan_cache.cache_key(platform, dtype.name, m_l, k, n_l, be,
@@ -276,7 +336,8 @@ def make_plan(m: int, k: int, n: int, *, dtype=jnp.float64,
         # heuristic, not break every GEMM in this bucket forever
         if tuned and all(
                 isinstance(tuned.get(x), int) and tuned[x] > 0
-                for x in ("bm", "bn", "bk")):
+                for x in ("bm", "bn", "bk")) and not (
+                tpu_pallas and _tpu_refusal(be, dtype, tuned, m_l, k, n_l)):
             blocks = {x: int(tuned[x]) for x in ("bm", "bn", "bk")}
             source = "tuned"
             # tuned n_slices was measured for the DEFAULT coverage target
@@ -296,6 +357,10 @@ def make_plan(m: int, k: int, n: int, *, dtype=jnp.float64,
     blocks["bm"] = bm or blocks["bm"]
     blocks["bn"] = bn or blocks["bn"]
     blocks["bk"] = bk or blocks["bk"]
+    if platform == "tpu":
+        reason = _tpu_refusal(be, dtype, blocks, m_l, k, n_l)
+        if reason:
+            raise ValueError(reason)
 
     slice_beta = None
     if be in _SLICED_BACKENDS:
@@ -303,7 +368,7 @@ def make_plan(m: int, k: int, n: int, *, dtype=jnp.float64,
 
         if slice_dtype is None and acc_dtype is None:
             slice_dtype, acc_dtype = _ozaki.platform_dtypes(platform)
-        target_bits = target_bits or OZAKI_TARGET_BITS[precision]
+        target_bits = target_bits or ozaki_target_bits(precision, dtype)
         # the fused kernel slices per K-slab (depth bk), the XLA path
         # slices the whole K — the exactness fixpoint sees that depth
         depth = blocks["bk"] if be == "ozaki-pallas" else k
@@ -314,7 +379,14 @@ def make_plan(m: int, k: int, n: int, *, dtype=jnp.float64,
         except ValueError as e:
             # K too deep for exact slicing in the accumulator dtype: the
             # plan degrades to the portable blocked-XLA backend rather
-            # than crashing the caller (tested in test_ozgemm_kernel.py)
+            # than crashing the caller (tested in test_ozgemm_kernel.py);
+            # the TPU refuses that backend, so there the plan fails
+            if platform == "tpu":
+                raise ValueError(
+                    f"ozaki slicing infeasible for this problem ({e}), and "
+                    f"the TPU refuses the 'xla' fallback; use "
+                    f"ozaki-pallas, whose slab depth bk bounds the "
+                    f"slicing") from e
             warnings.warn(
                 f"ozaki slicing infeasible for this problem ({e}); "
                 f"falling back to the 'xla' backend", RuntimeWarning,

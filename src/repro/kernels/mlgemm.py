@@ -43,10 +43,6 @@ from repro.core import mp
 
 __all__ = ["mlgemm_kernel_call"]
 
-# jax renamed TPUCompilerParams -> CompilerParams across 0.4.x/0.5.x
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or \
-    pltpu.TPUCompilerParams
-
 
 def _mlgemm_kernel(*refs, bk: int, nlimbs: int):
     # refs: nlimbs A-limb refs, nlimbs B-limb refs, nlimbs out refs,
@@ -63,18 +59,27 @@ def _mlgemm_kernel(*refs, bk: int, nlimbs: int):
 
     a = [r[...] for r in a_refs]  # (bm, bk) x nlimbs
     b = [r[...] for r in b_refs]  # (bk, bn) x nlimbs
+    lane = jax.lax.broadcasted_iota(jnp.int32, a[0].shape, 1)
+    sublane = jax.lax.broadcasted_iota(jnp.int32, b[0].shape, 0)
+
+    def pick(x, hit, axis):
+        # exact select of one column/row: every other term is +0.  Mosaic
+        # has no dynamic slice of a value, so a masked sum stands in for it
+        return jnp.sum(jnp.where(hit, x, jnp.zeros_like(x)), axis=axis,
+                       keepdims=True)
 
     def wave(i, carry):
         # one systolic wave: acc += outer(a_col, b_row) in tier arithmetic;
         # (bm, 1) x (1, bn) broadcasts through the EFT chains to the tile
-        a_col = mp.from_limbs(
-            [jax.lax.dynamic_slice_in_dim(x, i, 1, axis=1) for x in a])
-        b_row = mp.from_limbs(
-            [jax.lax.dynamic_slice_in_dim(x, i, 1, axis=0) for x in b])
+        a_col = mp.from_limbs([pick(x, lane == i, 1) for x in a])
+        b_row = mp.from_limbs([pick(x, sublane == i, 0) for x in b])
         out = mp.fma(mp.from_limbs(list(carry)), a_col, b_row)
         return tuple(mp.limbs(out))
 
-    acc = jax.lax.fori_loop(0, bk, wave, tuple(r[...] for r in acc_refs))
+    # int32 bounds: under x64 a python-int loop index is int64, which
+    # Mosaic cannot lower
+    acc = jax.lax.fori_loop(jnp.int32(0), jnp.int32(bk), wave,
+                            tuple(r[...] for r in acc_refs))
     for r, v in zip(acc_refs, acc):
         r[...] = v
 
@@ -118,7 +123,7 @@ def mlgemm_kernel_call(*limbs, bm: int, bn: int, bk: int,
             pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j))] * nlimbs,
         out_shape=out_shape,
         scratch_shapes=[pltpu.VMEM((bm, bn), dtype)] * nlimbs,
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

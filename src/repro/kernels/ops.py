@@ -50,8 +50,15 @@ def matmul_ml_xla(a, b, *, chunk: int = 16):
     m, k = a.shape
     _, n = b.shape
     kp = _round_up(k, chunk)
-    a = mp.from_limbs([_pad_to(l, m, kp) for l in mp.limbs(a)])
-    b = mp.from_limbs([_pad_to(l, kp, n) for l in mp.limbs(b)])
+    # opaque runtime operands (the engine._pad pin): under an outer jit
+    # with constant operands XLA would otherwise constant-fold the whole
+    # EFT chain without the fma contraction the compiled path applies to
+    # dd.mul's cross terms, and the two spellings would differ in the
+    # last bits
+    al, bl = jax.lax.optimization_barrier((
+        tuple(_pad_to(l, m, kp) for l in mp.limbs(a)),
+        tuple(_pad_to(l, kp, n) for l in mp.limbs(b))))
+    a, b = mp.from_limbs(al), mp.from_limbs(bl)
     nchunks = kp // chunk
 
     def body(acc, idx):

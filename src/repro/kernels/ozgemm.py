@@ -38,71 +38,12 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.core import dd, mp
-from repro.core.ozaki import _diagonal_pairs, _extract_slices, \
-    _normalize_slices
+from repro.core import mp
+from repro.core.ozaki import slab_update
 
 __all__ = ["ozgemm_kernel_call", "EPILOGUES"]
 
 EPILOGUES = ("none", "alpha", "full")
-
-# jax renamed TPUCompilerParams -> CompilerParams across 0.4.x/0.5.x
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or \
-    pltpu.TPUCompilerParams
-
-
-def _fold_diagonal(acc, prod):
-    """acc += prod (one diagonal's native-dtype sum) in acc's own tier.
-
-    ``prod`` may be wider than the limb dtype (f64 diagonal sums into an
-    f32-limb accumulator): the excess is split off exactly into a second
-    limb before the tier add, so nothing is lost to the narrowing cast.
-    """
-    limb_dtype = acc.limbs()[0].dtype
-    k = len(acc.limbs())
-    if prod.dtype == limb_dtype:
-        if isinstance(acc, dd.DD):
-            return dd.add_float(acc, prod)
-        # (k+1)-limb distillation: cheaper than lifting prod to a full
-        # tier add
-        return mp.from_limbs(mp.renorm_list(list(acc.limbs()) + [prod],
-                                            k=k, sweeps=3))
-    hi = prod.astype(limb_dtype)
-    lo = (prod - hi.astype(prod.dtype)).astype(limb_dtype)
-    if isinstance(acc, dd.DD):
-        return dd.add(acc, dd.from_hi_lo(hi, lo))
-    return mp.from_limbs(mp.renorm_list(list(acc.limbs()) + [hi, lo],
-                                        k=k, sweeps=3))
-
-
-def _slab_update(acc, a, b, *, beta, n_slices, slice_dtype, acc_dtype,
-                 full):
-    """One K-slab: extract slices, run the diagonal dots, fold into acc."""
-    limb_dtype = a.limbs()[0].dtype
-    sa = _extract_slices(a, beta, n_slices, axis=1)
-    sb = _extract_slices(b, beta, n_slices, axis=0)
-    narrow = jnp.dtype(slice_dtype) != jnp.dtype(limb_dtype)
-    if narrow:
-        # exact ladder normalization into the narrow dtype (shared with
-        # core.ozaki._ozaki_impl; pair (s, t) then carries the residual
-        # factor 2^(-(s+t)*beta), one rescale per diagonal)
-        sa, sc_a = _normalize_slices(sa, beta, 1, slice_dtype)
-        sb, sc_b = _normalize_slices(sb, beta, 0, slice_dtype)
-    n_diag = (2 * n_slices - 1) if full else n_slices
-    for d in range(n_diag):
-        # the pair dots of diagonal d sum in acc_dtype (exact by the
-        # slice_params headroom — every product sits on the diagonal's
-        # common grid), then fold into the multi-limb VMEM accumulator once
-        dsum = None
-        for s, t in _diagonal_pairs(d, n_slices):
-            p = jnp.dot(sa[s], sb[t],
-                        preferred_element_type=jnp.dtype(acc_dtype))
-            dsum = p if dsum is None else dsum + p
-        if narrow:
-            dsum = dsum.astype(limb_dtype) * \
-                (sc_a * sc_b * (2.0 ** (-d * beta)))
-        acc = _fold_diagonal(acc, dsum)
-    return acc
 
 
 def _ozgemm_kernel(*refs, nlimbs: int, beta: int, n_slices: int,
@@ -133,7 +74,7 @@ def _ozgemm_kernel(*refs, nlimbs: int, beta: int, n_slices: int,
 
     a = mp.from_limbs([r[...] for r in a_refs])  # (bm, bk)
     b = mp.from_limbs([r[...] for r in b_refs])  # (bk, bn)
-    acc = _slab_update(
+    acc = slab_update(
         mp.from_limbs([r[...] for r in acc_refs]), a, b,
         beta=beta, n_slices=n_slices,
         slice_dtype=slice_dtype, acc_dtype=acc_dtype, full=full)
@@ -195,7 +136,10 @@ def ozgemm_kernel_call(*operands, bm: int, bn: int, bk: int, beta: int,
 
     a_spec = pl.BlockSpec((bm, bk), lambda i, j, kk: (i, kk))
     b_spec = pl.BlockSpec((bk, bn), lambda i, j, kk: (kk, j))
-    scalar_spec = pl.BlockSpec((1, 1), lambda i, j, kk: (0, 0))
+    # int32 block indices: under x64 a python-int 0 is an i64, which
+    # Mosaic cannot return from an index map
+    scalar_spec = pl.BlockSpec((1, 1),
+                               lambda i, j, kk: (jnp.int32(0),) * 2)
     c_spec = pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j))
     in_specs = [a_spec] * nlimbs + [b_spec] * nlimbs
     if epilogue != "none":
@@ -214,7 +158,7 @@ def ozgemm_kernel_call(*operands, bm: int, bn: int, bk: int, beta: int,
         out_specs=[c_spec] * nlimbs,
         out_shape=[jax.ShapeDtypeStruct((m, n), dtype)] * nlimbs,
         scratch_shapes=[pltpu.VMEM((bm, bn), dtype)] * nlimbs,
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

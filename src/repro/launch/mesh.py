@@ -13,14 +13,9 @@ __all__ = ["make_production_mesh", "make_local_mesh", "compat_make_mesh"]
 
 
 def compat_make_mesh(shape, axes, devices=None):
-    """jax.make_mesh across jax versions.
-
-    axis_types/AxisType only landed after 0.4.x, and explicit Auto axes
-    keep newer versions from warning."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    kw = {} if axis_type is None else {
-        "axis_types": (axis_type.Auto,) * len(axes)}
-    return jax.make_mesh(shape, axes, devices=devices, **kw)
+    """jax.make_mesh with explicit Auto axes (keeps jax from warning)."""
+    return jax.make_mesh(shape, axes, devices=devices,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

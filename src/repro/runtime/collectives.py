@@ -3,7 +3,7 @@
 compensated_psum — the paper's high-precision accumulator, distributed.
 Cross-replica gradient reduction in f32 loses low bits as the replica count
 grows (and is order-dependent).  We split each operand into error-free
-mantissa slices (efts.mask_split, 12 bits each): the top-slice psum is
+mantissa slices (efts.split, 12 bits each): the top-slice psum is
 EXACT for up to 2^(24-2*12)=... practically the top slice sums exactly for
 thousands of replicas (12-bit values, f32 accumulator), and each further
 slice extends precision by 12 bits.  Recombination uses two_sum.  With
@@ -23,17 +23,26 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
-from repro.core.efts import mask_split, quick_two_sum, two_sum
+from repro.core.efts import quick_two_sum, split, two_sum
 
 __all__ = ["compensated_psum", "int8_psum_ef", "EFState"]
+
+# largest |x| whose Veltkamp splitter product x * (2^s + 1) stays finite
+_SPLIT_MAX = {jnp.dtype(jnp.float32): 2.0 ** 115,
+              jnp.dtype(jnp.float64): 2.0 ** 995}
 
 
 def compensated_psum(x, axis_name: str, slices: int = 2):
     """High-precision psum over a mesh axis via error-free slice reduction."""
+    # beyond the split's range (its splitter product would overflow) a
+    # value goes whole into the top slice: a plain psum, but finite
+    big = jnp.abs(x) > _SPLIT_MAX[jnp.dtype(x.dtype)]
     residual = x
     parts = []
     for _ in range(max(1, slices - 1)):
-        hi, residual = mask_split(residual)
+        hi, lo = split(jnp.where(big, 0, residual))
+        hi = jnp.where(big, residual, hi)
+        residual = jnp.where(big, 0, lo)
         parts.append(jax.lax.psum(hi, axis_name))
     parts.append(jax.lax.psum(residual, axis_name))
     # recombine with exact two_sum chain (descending magnitude)

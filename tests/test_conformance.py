@@ -33,6 +33,7 @@ import pytest
 from repro import gemm
 from repro.core import mp
 from repro.core.accuracy import max_rel_err as _rel_err
+from repro.core.accuracy import tier_ulp
 from repro.core.blas import rgemm
 from repro.core.linalg import lu_solve, rgetrf
 from repro.kernels.ref import ddgemm_ref, qdgemm_ref, tdgemm_ref
@@ -40,7 +41,7 @@ from repro.solve import rgesv
 
 # per-tier unit roundoff of one engine FMA (dd: two_prod slack dominates;
 # td/qd: the O(eps^k) renormalization truncation)
-ULP = {"dd": 2.0 ** -104, "td": 2.0 ** -155, "qd": 2.0 ** -205}
+ULP = {p: tier_ulp(p) for p in ("dd", "td", "qd")}
 REF = {"dd": ddgemm_ref, "td": tdgemm_ref, "qd": qdgemm_ref}
 
 # the support matrix: whole-K ozaki has no qd tier (rejected below,

@@ -100,7 +100,8 @@ def test_quick_two_sum_exact_when_ordered(a, b):
 @settings(max_examples=100, deadline=None)
 @given(finite64)
 def test_mask_split_exact(a):
-    hi, lo = efts.mask_split(jnp.float64(a))
+    """efts.split is error-free and its halves multiply exactly."""
+    hi, lo = efts.split(jnp.float64(a))
     assert _frac(hi) + _frac(lo) == _frac(a)
     # hi has at most 26 significant bits -> hi * hi is exact in f64
     assert _frac(float(hi) * float(hi)) == _frac(hi) * _frac(hi)
@@ -109,8 +110,9 @@ def test_mask_split_exact(a):
 @settings(max_examples=100, deadline=None)
 @given(finite32)
 def test_mask_split_exact_f32(a):
+    """efts.split over f32: error-free, cross products exact."""
     a32 = np.float32(a)
-    hi, lo = efts.mask_split(jnp.float32(a32))
+    hi, lo = efts.split(jnp.float32(a32))
     assert _frac(hi) + _frac(lo) == _frac(a32)
     # 12-bit halves: all cross products exact in f32
     assert _frac(np.float32(float(hi)) * np.float32(float(lo))) == _frac(hi) * _frac(lo)
@@ -121,7 +123,8 @@ def test_two_prod_jit_fused_broadcast(dtype):
     """Regression: the setting where fma contraction broke Dekker two_prod.
 
     jit-compile a fused broadcast (8,1)x(1,8) two_prod and verify the bound
-    elementwise against Fraction — this fails for the Veltkamp formulation.
+    elementwise against Fraction — this fails for Dekker's formulation,
+    which subtracts the rounded product from the partial products.
     """
     rng = np.random.default_rng(3)
     a = jnp.asarray(rng.standard_normal((8, 1)), dtype)

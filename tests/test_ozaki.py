@@ -106,3 +106,38 @@ def test_slice_bits_and_count():
     assert ozaki.slice_bits(64, jnp.float32, jnp.bfloat16) == 8
     assert ozaki.slice_bits(256, jnp.float64) == 22
     assert ozaki.slice_count(107, 6) == 19
+
+
+@pytest.mark.parametrize("backend,slice_dtype,acc_dtype", [
+    ("ozaki", None, None),                    # f64 slices on the CPU
+    ("ozaki-pallas", None, None),
+    ("ozaki-pallas", "bfloat16", "float32"),  # the TPU's MXU dtypes
+])
+def test_f32_limb_product_keeps_df32_accuracy(backend, slice_dtype,
+                                              acc_dtype):
+    """dd over f32 limbs (the TPU's tier) keeps ~49 bits: a diagonal sum
+    wider than the limbs is folded in its own dtype, not rounded to f32."""
+    from repro import gemm
+    from repro.core.accuracy import tier_ulp
+
+    rng = np.random.default_rng(11)
+    k = 48
+
+    def operand(shape):
+        x = rng.standard_normal(shape)
+        hi = x.astype(np.float32)
+        lo = (x - hi).astype(np.float32)
+        exact = hi.astype(np.float64) + lo  # the value the limbs hold
+        return dd.DD(jnp.asarray(hi), jnp.asarray(lo)), exact
+
+    a, a64 = operand((16, k))
+    b, b64 = operand((k, 12))
+    got = gemm.matmul(a, b, backend=backend, slice_dtype=slice_dtype,
+                      acc_dtype=acc_dtype, use_cache=False)
+    want = ddgemm_ref(dd.from_float(jnp.asarray(a64)),
+                      dd.from_float(jnp.asarray(b64)))
+    got64 = dd.from_float(jnp.asarray(np.asarray(got.hi, np.float64)
+                                      + np.asarray(got.lo, np.float64)))
+    err = np.abs(np.asarray(dd.to_float(dd.sub(got64, want))))
+    scale = max(1.0, float(np.abs(np.asarray(want.hi)).max()))
+    assert err.max() / scale < 16 * k * tier_ulp("dd", jnp.float32)

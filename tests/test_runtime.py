@@ -328,3 +328,18 @@ class TestShardingRules:
         x = jnp.ones((4, 4))
         np.testing.assert_array_equal(np.asarray(constrain(x, "batch", None)),
                                       np.ones((4, 4)))
+
+
+class TestCompensatedPsum:
+    @pytest.mark.parametrize("dtype", [jnp.float32, jnp.float64])
+    def test_exact_and_finite_across_the_range(self, dtype):
+        from jax.sharding import Mesh, PartitionSpec as P
+        from repro.runtime.collectives import compensated_psum
+
+        fi = jnp.finfo(dtype)
+        x = jnp.asarray([1.0 / 3, -2.5e-30, float(fi.max) / 2,
+                         -float(fi.max) / 7, 7.0], dtype)
+        mesh = Mesh(np.array(jax.devices()[:1]), ("d",))
+        out = jax.shard_map(lambda v: compensated_psum(v, "d", slices=3),
+                            mesh=mesh, in_specs=P("d"), out_specs=P())(x)
+        np.testing.assert_array_equal(np.asarray(out), np.asarray(x))
