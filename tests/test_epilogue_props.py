@@ -102,7 +102,11 @@ class TestBetaZeroDoesNotReadC:
     @pytest.mark.parametrize("bad", ["nan", "inf"])
     @pytest.mark.parametrize("backend,precision", [
         ("xla", "dd"), ("xla", "qd"), ("ref", "dd"),
-        ("pallas", "dd"), ("ozaki-pallas", "dd"), ("ozaki-pallas", "qd"),
+        ("pallas", "dd"), ("ozaki-pallas", "dd"),
+        # the fused alpha drain at qd: XLA:CPU had not compiled it in
+        # interpret mode after ten minutes
+        pytest.param("ozaki-pallas", "qd",
+                     marks=[pytest.mark.slow, pytest.mark.timeout(3600)]),
     ])
     def test_static_zero_beta_guards_poisoned_c(self, backend, precision,
                                                 bad, tmp_cache):

@@ -113,8 +113,13 @@ def test_rtrsm_rejects_unknown_side():
         rtrsm(t, t, side="middle")
 
 
-@pytest.mark.parametrize("precision", ["dd", "qd"])
-@pytest.mark.parametrize("n,nb", [(8, 3), (12, 5), (9, 9), (10, 4)])
+# At qd each case with more than one panel compiles its own panels, for
+# about a minute under load: those are slow.  The dd column runs every
+# width, and test_linalg_gates keeps the qd blocked LU in tier 1.
+@pytest.mark.parametrize("n,nb,precision", [
+    pytest.param(n, nb, p, marks=[pytest.mark.slow, pytest.mark.timeout(300)]
+                 if p == "qd" and nb < n else [])
+    for p in ("dd", "qd") for n, nb in [(8, 3), (12, 5), (9, 9), (10, 4)]])
 def test_rgetrf_blocked_matches_unblocked(precision, n, nb):
     a = _rand(precision, (n, n), n * 7 + nb)
     full, piv_full = rgetrf2(a)
@@ -151,10 +156,13 @@ if _HAVE_HYPOTHESIS:
                             jnp.asarray(piv), inverse=True)
         assert max_rel_err(back, x) == 0.0
 
-    @given(st.integers(min_value=2, max_value=10),
+    # Shapes come from small fixed sets, so that examples reuse compiled
+    # programs, and the draws are derandomized, so that every run takes the
+    # same examples and the same time; the data stays random.
+    @given(st.sampled_from([3, 7]),
            st.booleans(), st.booleans(), st.sampled_from(["left", "right"]),
            st.integers(min_value=0, max_value=2 ** 31 - 1))
-    @settings(max_examples=15, deadline=None)
+    @settings(max_examples=15, deadline=None, derandomize=True)
     def test_prop_rtrsm_reconstructs_b(n, lower, unit_diag, side, seed):
         rng = np.random.default_rng(seed)
         t = mp.from_float(jnp.asarray(_tri(rng, n, lower=lower,
@@ -165,10 +173,10 @@ if _HAVE_HYPOTHESIS:
         recon = REF["dd"](t, x) if side == "left" else REF["dd"](x, t)
         assert max_rel_err(recon, b) < 64 * n * ULP["dd"]
 
-    @given(st.integers(min_value=2, max_value=14),
-           st.integers(min_value=1, max_value=14),
+    @given(st.sampled_from([5, 8]),
+           st.sampled_from([3, 8]),  # a ragged last panel, and one panel
            st.integers(min_value=0, max_value=2 ** 31 - 1))
-    @settings(max_examples=15, deadline=None)
+    @settings(max_examples=15, deadline=None, derandomize=True)
     def test_prop_rgetrf_block_invariance(n, nb, seed):
         a = _rand("dd", (n, n), seed % 10_000)
         full, piv_full = rgetrf2(a)

@@ -3,6 +3,7 @@ compensated collectives, sharding rules."""
 
 import dataclasses
 import os
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
@@ -165,9 +166,12 @@ np.testing.assert_array_equal(np.asarray(restored["w"]),
 assert restored["w"].sharding.mesh.shape["data"] == 2
 print("ELASTIC_OK")
 """
-        env = dict(os.environ, PYTHONPATH="src")
-        out = subprocess.run([sys.executable, "-c", code], cwd="/root/repo",
-                             capture_output=True, text=True, env=env)
+        src = Path(__file__).resolve().parents[1] / "src"
+        env = dict(os.environ, PYTHONPATH=os.pathsep.join(
+            filter(None, [str(src), os.environ.get("PYTHONPATH")])))
+        out = subprocess.run([sys.executable, "-c", code], cwd=tmp_path,
+                             capture_output=True, text=True, env=env,
+                             timeout=60)
         assert "ELASTIC_OK" in out.stdout, out.stderr[-2000:]
 
 

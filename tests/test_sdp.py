@@ -29,7 +29,6 @@ def _theta_double():
     return _RESULTS["f64"]
 
 
-@pytest.mark.slow
 def test_binary128_reaches_table_v_band():
     res = _theta_dd()
     # Table V band: relative gaps 1e-22..1e-31, feasibility errors <= 1e-24
@@ -38,7 +37,6 @@ def test_binary128_reaches_table_v_band():
     assert res.d_feas_err < 1e-28
 
 
-@pytest.mark.slow
 def test_double_stalls_binary128_does_not():
     rd = _theta_double()
     rq = _theta_dd()
@@ -46,7 +44,6 @@ def test_double_stalls_binary128_does_not():
     assert rq.relative_gap < 1e-10 * rd.relative_gap
 
 
-@pytest.mark.slow
 def test_objective_agreement():
     # theta number of this graph is integral here (=4): both precisions agree
     rd = _theta_double()
@@ -63,6 +60,7 @@ def test_double_on_random_sdp_known_optimum():
 
 
 @pytest.mark.slow
+@pytest.mark.timeout(900)  # two PDIPM solves: about 6 minutes on a CPU
 def test_qd_tier_descends_past_the_dd_floor():
     # ISSUE-2 acceptance: binary128+ reaches <= 1e-20 on random_sdp where
     # the dd tier floors higher.  degeneracy=1e-5 makes two constraints
@@ -91,6 +89,8 @@ def test_qd_tier_descends_past_the_dd_floor():
     assert st["factorizations"].get("dd", 0) > 0, st
 
 
+@pytest.mark.slow
+@pytest.mark.timeout(300)  # compiles the td PDIPM: 60-85 s on a CPU
 def test_binary192_tier_solves_and_overrides_schur_factor():
     # the td rung of the SDP precision axis: binary192 runs the same PDIPM
     # in 3-limb arithmetic, converging where double stalls, and the Schur

@@ -81,21 +81,26 @@ def test_escalation_triggers_exactly_on_stagnation():
     assert all(t == "dd" for t in info.factor_tiers[esc_it:])
 
 
-def test_backward_error_history_monotone_non_increasing():
-    for seed, (ft, tt) in enumerate(LADDER_CELLS):
-        a, b, _ = _system(seed=seed)
-        _, info = rgesv(a, b, factor_tier=ft, target_tier=tt, backend="xla")
-        h = info.backward_errors
-        assert all(later <= earlier for earlier, later in zip(h, h[1:])), h
+@pytest.mark.parametrize("factor_tier,target_tier", LADDER_CELLS)
+def test_backward_error_history_monotone_non_increasing(factor_tier,
+                                                        target_tier):
+    seed = LADDER_CELLS.index((factor_tier, target_tier))
+    a, b, _ = _system(seed=seed)
+    _, info = rgesv(a, b, factor_tier=factor_tier, target_tier=target_tier,
+                    backend="xla")
+    h = info.backward_errors
+    assert all(later <= earlier for earlier, later in zip(h, h[1:])), h
 
 
-def test_nan_factor_breakdown_escalates_and_recovers():
+@pytest.mark.parametrize("ladder", ["default", "three-rung"])
+def test_nan_factor_breakdown_escalates_and_recovers(ladder):
     # SPD with an eigenvalue (1e-40) far below dd resolution of the large
     # ones: the dd Cholesky goes indefinite under rounding and NaNs; the
     # solver must escalate one rung and still converge.  On the default
     # ladder the next rung is td (~159 bits, resolving cond ~1e40 with
     # room to spare), so the breakdown recovers WITHOUT a qd
     # factorization; the old three-rung ladder must still climb to qd.
+    # One ladder a case: each compiles its own rungs.
     n = 6
     rng = np.random.default_rng(5)
     q, _ = np.linalg.qr(rng.standard_normal((n, n)))
@@ -104,20 +109,21 @@ def test_nan_factor_breakdown_escalates_and_recovers():
     b_mat = matmul(matmul(qq, d, backend="xla"),
                    mp.map_limbs(lambda l: l.T, qq), backend="xla")
     rhs = mp.from_float(jnp.asarray(rng.standard_normal((n, 1))), "qd")
+    kw = {} if ladder == "default" else {"ladder": ("f64", "dd", "qd")}
     x, info = rposv(b_mat, rhs, factor_tier="dd", target_tier="qd",
-                    backend="xla", max_iters=20, tol=1e-30)
+                    backend="xla", max_iters=20, tol=1e-30, **kw)
     assert info.converged, info.backward_errors
-    assert len(info.escalations) == 1
-    assert info.factorizations == {"dd": 1, "td": 1}
-    assert np.isfinite(np.asarray(mp.to_float(x))).all()
-    # the pre-td ladder spelling still climbs straight to qd
-    _, info_old = rposv(b_mat, rhs, factor_tier="dd", target_tier="qd",
-                        backend="xla", max_iters=20, tol=1e-30,
-                        ladder=("f64", "dd", "qd"))
-    assert info_old.converged and "qd" in info_old.factorizations
+    if ladder == "default":
+        assert len(info.escalations) == 1
+        assert info.factorizations == {"dd": 1, "td": 1}
+        assert np.isfinite(np.asarray(mp.to_float(x))).all()
+    else:
+        # the pre-td ladder spelling still climbs straight to qd
+        assert "qd" in info.factorizations
 
 
-def test_td_rung_spares_the_qd_factorization():
+@pytest.mark.parametrize("ladder", ["default", "three-rung"])
+def test_td_rung_spares_the_qd_factorization(ladder):
     # The td rung's reason to exist: a system whose conditioning sits
     # between dd's reach (1/u_dd ~ 1e32) and td's (1/u_td ~ 7e47).
     # Hilbert n=26 (cond ~ 1e38) formed IN qd arithmetic — a multi-limb
@@ -128,7 +134,7 @@ def test_td_rung_spares_the_qd_factorization():
     # Receipt (the ISSUE acceptance criterion): on the default ladder the
     # solver climbs f64 -> dd -> td and never factors qd; on the old
     # three-rung ladder (f64, dd, qd) the same system must pay for a full
-    # qd factorization.
+    # qd factorization.  One ladder a case: each compiles its own rungs.
     n = 26
     i = jnp.arange(n, dtype=jnp.float64)
     denom = i[:, None] + i[None, :] + 1.0
@@ -136,23 +142,22 @@ def test_td_rung_spares_the_qd_factorization():
                mp.from_float(denom, "qd"))
     b = matmul(h, mp.from_float(jnp.ones((n, 1)), "qd"), backend="xla")
 
-    x_new, info_new = rgesv(h, b, target_tier="qd", backend="xla",
-                            max_iters=40)
-    assert info_new.converged, info_new.backward_errors
-    assert "qd" not in info_new.factorizations, info_new.factorizations
-    assert info_new.factorizations.get("td", 0) >= 1
-    assert [(e["from"], e["to"]) for e in info_new.escalations] == \
-        [("f64", "dd"), ("dd", "td")]
-    assert info_new.factor_tiers[-1] == "td"
-
-    x_old, info_old = rgesv(h, b, target_tier="qd", backend="xla",
-                            max_iters=40, ladder=("f64", "dd", "qd"))
-    assert info_old.converged, info_old.backward_errors
-    assert info_old.factorizations.get("qd", 0) >= 1, \
-        info_old.factorizations
-    # both ladders land the same answer at qd accuracy
-    assert np.abs(np.asarray(mp.to_float(mp.sub(x_new, x_old)))).max() \
-        < 1e-25
+    kw = {} if ladder == "default" else {"ladder": ("f64", "dd", "qd")}
+    x, info = rgesv(h, b, target_tier="qd", backend="xla", max_iters=40,
+                    **kw)
+    assert info.converged, info.backward_errors
+    if ladder == "default":
+        assert "qd" not in info.factorizations, info.factorizations
+        assert info.factorizations.get("td", 0) >= 1
+        assert [(e["from"], e["to"]) for e in info.escalations] == \
+            [("f64", "dd"), ("dd", "td")]
+        assert info.factor_tiers[-1] == "td"
+    else:
+        assert info.factorizations.get("qd", 0) >= 1, info.factorizations
+    # both ladders land the exact solution (all ones) at qd accuracy:
+    # within 5e-26 each, so within 1e-25 of each other
+    ones = mp.from_float(jnp.ones((n, 1)), "qd")
+    assert np.abs(np.asarray(mp.to_float(mp.sub(x, ones)))).max() < 5e-26
 
 
 def test_ladder_override_validation():

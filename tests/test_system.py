@@ -7,7 +7,6 @@ import numpy as np
 import pytest
 
 
-@pytest.mark.slow
 def test_train_loss_decreases_and_failover_recovers(tmp_path):
     from repro.launch.train import train
 
@@ -21,7 +20,6 @@ def test_train_loss_decreases_and_failover_recovers(tmp_path):
         losses[:10], losses[-10:])
 
 
-@pytest.mark.slow
 def test_train_ssm_family(tmp_path):
     from repro.configs.base import RunConfig
     from repro.launch.train import train

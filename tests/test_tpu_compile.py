@@ -66,8 +66,14 @@ def _limbs(shape, nl, sharding, dtype=jnp.float32):
     return [jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)] * nl
 
 
+# XLA's default level, which the chip's compiles get: the root conftest
+# lowers XLA_FLAGS' level for the CPU, and that flag reaches these compiles
+XLA_DEFAULT_OPT_LEVEL = 3
+
+
 def _compile(fn, *args) -> str:
-    return jax.jit(fn).lower(*args).compile().as_text()
+    return jax.jit(fn).lower(*args).compile(compiler_options={
+        "xla_backend_optimization_level": XLA_DEFAULT_OPT_LEVEL}).as_text()
 
 
 def _tpu_plan(precision="dd", dtype=jnp.float32, **kw):
@@ -80,16 +86,23 @@ def _tpu_plan(precision="dd", dtype=jnp.float32, **kw):
 # ---------------------------------------------------------------------------
 
 
-@pytest.mark.parametrize("epilogue", ["none", "full"])
-def test_ozgemm_kernel_compiles_at_tpu_plan_blocks(one_chip, epilogue):
-    """"full" is the fused alpha/beta drain of the LU's trailing update."""
+@pytest.mark.parametrize("precision,epilogue", [
+    pytest.param("dd", "none", id="none"),
+    pytest.param("dd", "full", id="full"),
+    pytest.param("td", "full", id="td-full"),
+])
+def test_ozgemm_kernel_compiles_at_tpu_plan_blocks(one_chip, precision,
+                                                   epilogue):
+    """"full" is the fused alpha/beta drain of the LU's trailing update;
+    at td it is the program the LU cell runs on the chip."""
     from repro.kernels.ozgemm import ozgemm_kernel_call
 
-    plan = _tpu_plan()
-    operands = _limbs((256, 256), 4, one_chip)
+    plan = _tpu_plan(precision)
+    nl = mp.PRECISIONS[precision]
+    operands = _limbs((256, 256), 2 * nl, one_chip)
     if epilogue == "full":
-        operands += _limbs((1, 1), 4, one_chip) + _limbs((256, 256), 2,
-                                                         one_chip)
+        operands += _limbs((1, 1), 2 * nl, one_chip) + _limbs((256, 256), nl,
+                                                              one_chip)
     txt = _compile(
         lambda *l: ozgemm_kernel_call(
             *l, bm=plan.bm, bn=plan.bn, bk=plan.bk, beta=plan.slice_beta,
